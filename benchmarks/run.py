@@ -30,6 +30,7 @@ from benchmarks import (
     table2_real,
 )
 from benchmarks.common import bench_json_path, write_bench_json
+from repro.launch.compile_cache import enable_compile_cache
 
 
 BENCHES = [
@@ -61,6 +62,7 @@ def main() -> None:
                     help="published experiment sizes (slow)")
     ap.add_argument("--only", default=None, help="substring filter")
     args = ap.parse_args()
+    enable_compile_cache()
 
     failures = []
     summary_rows = []

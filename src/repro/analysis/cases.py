@@ -26,7 +26,6 @@ from repro.core import transport as transport_core
 from repro.core.compression import Compression
 from repro.core.dantzig import DantzigConfig
 from repro.core.distributed import (
-    _shard_map,
     distributed_mc_slda_shardmap,
     distributed_slda_shardmap,
 )
@@ -246,7 +245,9 @@ def _worker_rounds_case(cfg, t_rounds, comp=None, agg=None, faults=False,
             return beta
 
         spec = P("data", None)
-        fn = _shard_map(shard_fn, mesh, (spec, spec) + plan_specs, P())
+        fn = jax.shard_map(shard_fn, mesh=mesh,
+                           in_specs=(spec, spec) + plan_specs, out_specs=P(),
+                           check_vma=False)
         return fn, (x, y) + plan_args
     return build
 

@@ -249,6 +249,11 @@ class AxisPayloadBits(NamedTuple):
         return violations
 
 
+def _block_dim(dim) -> int:
+    """One block dimension as an int (``pl.Blocked`` wraps it)."""
+    return int(getattr(dim, "block_size", dim))
+
+
 class VmemConformance(NamedTuple):
     """Cross-check traced fused-ADMM launches against the VMEM model.
 
@@ -257,24 +262,31 @@ class VmemConformance(NamedTuple):
     (d, block_k, state_io), and assert the analytic footprint
     ``fused_block_vmem_bytes(d, block_k, state_io)`` fits the budget and
     that ``block_k`` never exceeds what ``pick_block_k`` would allow.
+    A launch whose name or block mappings cannot be read is itself a
+    violation: the contract never passes by skipping what it cannot see.
     """
 
     budget: Optional[IntOrParam] = None  # None -> backend_vmem_budget()
-    kernel_substr: str = "_fused_admm"
+    kernel_substr: str = "fused_admm"
 
     def describe(self) -> str:
         budget = self.budget if self.budget is not None else "backend"
         return f"vmem[{self.kernel_substr} <= {budget}]"
 
-    def _kernel_name(self, eqn) -> str:
-        info = eqn.params.get("name_and_src_info", None)
-        name = getattr(info, "name", None)
-        if name is None:
-            name = eqn.params.get("name", "") or str(info or "")
+    @staticmethod
+    def _kernel_name(eqn) -> str:
+        """The launch's ``name=``, else the kernel function's name."""
+        name = eqn.params.get("name")
+        if not name:
+            info = getattr(eqn.params.get("jaxpr"), "debug_info", None)
+            name = getattr(info, "func_name", None)
+        if not name:
+            raise ValueError("pallas_call carries no kernel name")
         return name
 
     def check(self, jaxpr, params=None) -> list:
         from repro.kernels.dantzig_fused import (
+            STATE_KERNEL,
             backend_vmem_budget,
             fused_block_vmem_bytes,
             pick_block_k,
@@ -285,21 +297,23 @@ class VmemConformance(NamedTuple):
             budget = backend_vmem_budget()
         violations = []
         for site in walker.find_eqns(jaxpr, "pallas_call"):
-            if self.kernel_substr not in self._kernel_name(site.eqn):
-                continue
             try:
-                gm = site.eqn.params["grid_mapping"]
-                mappings = gm.block_mappings
-                d = int(mappings[0].block_shape[0])
-                block_k = int(mappings[3].block_shape[1])
-                k_total = int(mappings[3].array_shape_dtype.shape[1])
-                state_io = int(gm.num_inputs) > 6
-            except (KeyError, AttributeError, IndexError, TypeError) as exc:
+                name = self._kernel_name(site.eqn)
+                if self.kernel_substr not in name:
+                    continue
+                mappings = site.eqn.params["grid_mapping"].block_mappings
+                # the last two dims: a vmapped launch prepends a batch dim
+                d = _block_dim(mappings[0].block_shape[-2])
+                block_k = _block_dim(mappings[3].block_shape[-1])
+                k_total = int(mappings[3].array_aval.shape[-1])
+                state_io = name == STATE_KERNEL
+            except (KeyError, AttributeError, IndexError, TypeError,
+                    ValueError) as exc:
                 violations.append(Violation(
                     self.describe(),
-                    f"could not read block mappings from pallas_call "
-                    f"params ({exc!r}); analyzer needs updating for this "
-                    f"jax version",
+                    f"could not read the kernel name or block mappings "
+                    f"from pallas_call params ({exc!r}); the analyzer "
+                    f"needs updating for this jax version",
                     _fmt([site]),
                 ))
                 continue

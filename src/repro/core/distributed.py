@@ -64,17 +64,6 @@ from repro.core.pipeline import BinaryHead, MulticlassHead
 from repro.core.transport import CommPlan
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """shard_map across JAX versions (``check_vma`` vs ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
-
-
 def _materialize_plan(faults, mesh, data_axes, rounds, staleness):
     """Resolve ``faults`` to a full (m, rounds) :class:`FaultPlan`.
 
@@ -201,7 +190,9 @@ def distributed_slda_shardmap(
         )
         return slda.hard_threshold(beta_bar[:, 0], t)
 
-    fn = _shard_map(shard_fn, mesh, (in_spec, in_spec) + plan_specs, P())
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(in_spec, in_spec) + plan_specs,
+                       out_specs=P(), check_vma=False)
     return fn(x, y, *plan_args)
 
 
@@ -309,10 +300,10 @@ def distributed_mc_slda_shardmap(
             means = jax.lax.pmean(means, ax)
         return slda.hard_threshold(beta_bar, t), means
 
-    fn = _shard_map(
-        shard_fn, mesh,
-        (P(data_axes, None), P(data_axes)) + plan_specs, (P(), P())
-    )
+    fn = jax.shard_map(
+        shard_fn, mesh=mesh,
+        in_specs=(P(data_axes, None), P(data_axes)) + plan_specs,
+        out_specs=(P(), P()), check_vma=False)
     return fn(x, labels, *plan_args)
 
 
@@ -334,7 +325,9 @@ def naive_averaged_slda_shardmap(
             beta_hat = jax.lax.pmean(beta_hat, ax)
         return beta_hat
 
-    fn = _shard_map(shard_fn, mesh, (P(data_axes, None), P(data_axes, None)), P())
+    fn = jax.shard_map(shard_fn, mesh=mesh,
+                       in_specs=(P(data_axes, None), P(data_axes, None)),
+                       out_specs=P(), check_vma=False)
     return fn(x, y)
 
 

@@ -9,9 +9,9 @@ problem shape and config:
     The ``lax.scan`` ADMM in :func:`repro.core.dantzig.solve_dantzig_scan`.
     Selected when ``cfg.fused`` is False (it is the only path with
     residual-balancing adaptive rho), or as the fallback when the fused
-    kernel cannot fit even one column block in the fast-memory budget
-    (the two (d, d) operands A and Q alone exceed it, d ≳ 1250 at f32
-    with the default TPU 12 MiB budget).
+    kernel cannot fit even one 128-column block in the fast-memory
+    budget (d ≳ 2200 at f32 in the fixed kernel, d ≳ 2000 with state
+    I/O, under the v5e budget).
 
 ``fused``
     The Pallas kernel in :mod:`repro.kernels.dantzig_fused` with the
@@ -25,10 +25,9 @@ problem shape and config:
 
 The fast-memory budget is ``cfg.vmem_budget`` when set, else derived
 from the backend (:func:`repro.kernels.dantzig_fused.backend_vmem_budget`):
-TPU gets the 12 MiB VMEM budget, CPU mirrors it so shapes validated
-under the interpreter pick the TPU's path, and GPU gets a shared-memory
--sized budget that routes realistic CLIME shapes to the scan solver
-(the fused kernel is a TPU design).
+TPU reads the attached chip's VMEM, CPU mirrors the v5e so shapes
+validated under the interpreter pick the chip's path, and any other
+backend raises (the fused kernel is a TPU design).
 
 Every entry point accepts either the raw (d, d) matrix or its
 :class:`~repro.kernels.spectral.SpectralFactor`; a factor is threaded

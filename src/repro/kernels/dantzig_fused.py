@@ -50,9 +50,10 @@ block's max scaled-ADMM residual IN VMEM (no HBM round trip):
 copies) and stops the whole block when ``max(r_pri, s_dual) <= tol``,
 capped at exactly ``max_iters`` iterations (the final chunk is
 clamped when ``check_every`` does not divide it).  The executed
-iteration count per block rides out as an extra (1, num_blocks) int32
-output.  The adaptive kernel also takes and returns the full ADMM
-state ``(z, w, u1, u2)`` (:class:`AdmmState`), so a solve can RESUME
+iteration count per block rides out as an extra int32 output, one
+(1, 128) lane tile per block.  The adaptive kernel also takes and
+returns the full ADMM state ``(z, w, u1, u2)`` (:class:`AdmmState`),
+so a solve can RESUME
 from an earlier solution -- glmnet-style warm starts across lambda-path
 re-sweeps -- instead of restarting from zero.  ``tol=None`` keeps the
 original fixed-iteration kernel (bit-exact with the pre-adaptive
@@ -67,28 +68,50 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.spectral import SpectralFactor
 
-# Per-core VMEM is ~16 MiB; leave headroom for Mosaic's own buffers,
-# semaphores and the pipeline's double-buffered operand copies.
-DEFAULT_VMEM_BUDGET = 12 * 2**20
-
-# Per-backend fast-memory budgets for the blocking model.
-#   tpu: the VMEM budget above.
-#   cpu: mirrors the TPU budget on purpose -- the Pallas interpreter has
-#        no real VMEM limit, but honoring the same blocking means shapes
-#        validated on CPU pick the same scan/fused/fused_blocked path
-#        they will pick on TPU (see DESIGN.md §5).
-#   gpu: the kernel keeps A and Q resident, which maps to shared memory
-#        on GPU (~228 KB on H100); with headroom that routes realistic
-#        CLIME shapes (d >= ~128) to the XLA scan solver, which is the
-#        right call -- the fused design is a TPU design.
-BACKEND_VMEM_BUDGETS = {
-    "tpu": DEFAULT_VMEM_BUDGET,
-    "cpu": DEFAULT_VMEM_BUDGET,
-    "gpu": 192 * 2**10,
+# VMEM per TensorCore, keyed by ``jax.Device.device_kind``.  The v5e
+# figure is what its compiler reports ("Used ... of 128.00M vmem").
+CHIP_VMEM_BYTES = {
+    "TPU v5 lite": 128 * 2**20,
 }
+# The chip these kernels are written for.  The CPU backend runs them in
+# the Pallas interpreter, which has no VMEM, so it borrows this chip's
+# figures: shapes validated on CPU then pick the scan/fused/
+# fused_blocked path they will pick on the chip (DESIGN.md §5).
+TARGET_CHIP = "TPU v5 lite"
+# The blocking model plans for 3/4 of VMEM; the kernel's scoped limit is
+# all of it, so the model may be off by a quarter without a refusal.
+_BUDGET_NUM, _BUDGET_DEN = 3, 4
+DEFAULT_VMEM_BUDGET = CHIP_VMEM_BYTES[TARGET_CHIP] * _BUDGET_NUM // _BUDGET_DEN
+
+# f32 tiles are (8 sublanes, 128 lanes): a block's last two dims are
+# padded up to them in VMEM, and a column block must be a multiple of
+# 128 lanes unless it spans the whole batch.
+_SUBLANES, _LANES = 8, 128
+# Scratch beyond the double-buffered operands, as counts of (d, block_k)
+# and (d, d) buffers by ``state_io``: the live values of one ADMM
+# iteration, and the bf16 parts a full-f32 MXU product splits its
+# operands into.  Fitted as an upper bound to the least scoped VMEM
+# limit Mosaic accepts on v5e at (d, block_k) in {(200, 200),
+# (256, 512), (512, 256), (1024, 128), (2048, 128)}: the model exceeds
+# it by 0.2-52% (fixed) and 1-89% (state I/O).
+_TEMPORARIES = {False: (14, 2), True: (22, 5)}
+# Mosaic's internal scratch, outside the operand and temporary buffers.
+_INTERNAL_SCRATCH = 2**20
+# Mosaic unrolls a block's vector ops, so compile time grows with
+# d * block_k.  For v5e at d=1024: 3 s (fixed kernel) and 7 s (state
+# kernel) at block_k=128, 40 s and 67 s at block_k=1024 and 640.  Blocks
+# stay under this many elements (but never under 128 columns); each
+# extra grid step re-reads A and Q once, d^2 floats against the
+# block's ~8 * iters * d^2 * block_k flops.
+_MAX_BLOCK_ELEMS = 2**17
+
+# pallas_call names of the two kernels (trace contracts match on them)
+FIXED_KERNEL = "fused_admm"
+STATE_KERNEL = "fused_admm_state"
 
 
 class AdmmState(NamedTuple):
@@ -120,65 +143,96 @@ class FusedSolveResult(NamedTuple):
     iters: jnp.ndarray  # (num_blocks,) int32 executed iterations per block
 
 
-def backend_vmem_budget(backend: str | None = None) -> int:
-    """Fast-memory budget for ``backend`` (None = the active backend)."""
+def chip_vmem_bytes(backend: str | None = None) -> int:
+    """VMEM of the chip that ``backend`` (None = the active one) compiles for.
+
+    TPU reads the attached chip's ``device_kind``; CPU stands in for
+    :data:`TARGET_CHIP`.  Any other backend, or a chip missing from
+    :data:`CHIP_VMEM_BYTES`, raises: a guessed figure would send shapes
+    to a kernel the compiler refuses.
+    """
     if backend is None:
         backend = jax.default_backend()
-    return BACKEND_VMEM_BUDGETS.get(backend, DEFAULT_VMEM_BUDGET)
+    if backend == "tpu":
+        kind = jax.devices("tpu")[0].device_kind
+    elif backend == "cpu":
+        kind = TARGET_CHIP
+    else:
+        raise ValueError(
+            f"no VMEM model for backend {backend!r}: the fused ADMM kernel "
+            "is a TPU kernel (CPU runs it in the Pallas interpreter)")
+    if kind not in CHIP_VMEM_BYTES:
+        raise ValueError(
+            f"no VMEM figure for device kind {kind!r}; add it to "
+            "CHIP_VMEM_BYTES in repro.kernels.dantzig_fused")
+    return CHIP_VMEM_BYTES[kind]
+
+
+def backend_vmem_budget(backend: str | None = None) -> int:
+    """Bytes the blocking model may plan for on ``backend``'s chip."""
+    return chip_vmem_bytes(backend) * _BUDGET_NUM // _BUDGET_DEN
+
+
+def _tile_bytes(rows: int, cols: int) -> int:
+    """VMEM bytes of one f32 (rows, cols) buffer after (8, 128) tiling."""
+    rows = -(-rows // _SUBLANES) * _SUBLANES
+    cols = -(-cols // _LANES) * _LANES
+    return 4 * rows * cols
 
 
 def fused_block_vmem_bytes(d: int, block_k: int, state_io: bool = False) -> int:
-    """f32 VMEM footprint of one grid step of the fused kernel.
+    """VMEM footprint of one grid step of the fused kernel.
 
-    Fixed mode: a, q: d*d each; inv: d; b, out: d*block_k; lam, rho:
-    block_k; ADMM state (z, w, u1, u2): 4*d*block_k; loop temporaries
-    (beta, ab, relaxed copies): ~3*d*block_k.
-
-    ``state_io`` (the adaptive / warm-start kernel) additionally
-    streams the 4-leaf :class:`AdmmState` both IN and OUT and carries
-    the last-iteration deltas (dz, dw) for the dual residual: b + 4
-    state-in + 4 state-out + ~5 temporaries = 14 (d, block_k) arrays,
-    plus the residual row temporaries.
+    Every operand and result block is double-buffered by the Pallas
+    pipeline, even A and Q, whose block never moves.  Fixed mode reads
+    A, Q (d, d), inv (d, 1), b (d, block_k) and lam, rho (1, block_k),
+    and writes out (d, block_k).  ``state_io`` (the adaptive /
+    warm-start kernel) also reads and writes the four (d, block_k)
+    :class:`AdmmState` leaves and writes a (1, 128) iteration count.
+    On top come the :data:`_TEMPORARIES` and Mosaic's internal scratch.
+    Each buffer is padded to (8, 128) tiles.
     """
-    per_col = 14 if state_io else 9
-    rows = 4 if state_io else 2
-    return 4 * (2 * d * d + d + per_col * d * block_k + rows * block_k)
+    col = _tile_bytes(d, block_k)
+    row = _tile_bytes(1, block_k)
+    mat = _tile_bytes(d, d)
+    inputs = 2 * mat + _tile_bytes(d, 1) + col + 2 * row
+    outputs = col
+    if state_io:
+        inputs += 4 * col
+        outputs += 4 * col + _tile_bytes(1, _LANES)
+    n_col, n_mat = _TEMPORARIES[state_io]
+    return (2 * (inputs + outputs) + n_col * col + n_mat * mat
+            + _INTERNAL_SCRATCH)
 
 
 def pick_block_k(d: int, k: int, budget: int = DEFAULT_VMEM_BUDGET,
                  state_io: bool = False) -> int | None:
-    """Largest column-block size whose grid step fits the VMEM budget.
+    """Widest column block that fits the VMEM budget and compiles fast.
 
-    Returns ``k`` when the whole batch fits in one block, a smaller
-    (lane-friendly) block size when it must be tiled, or ``None`` when
-    even a single column cannot fit (A + Q alone blow the budget) --
-    callers fall back to the XLA scan solver in that case.
-    ``state_io`` selects the adaptive kernel's larger per-column
-    footprint (see :func:`fused_block_vmem_bytes`).
+    Returns ``k`` when the whole batch fits in one block, else the
+    largest multiple of 128 below ``k`` that fits (Mosaic accepts no
+    other column block), or ``None`` when no such block fits -- callers
+    fall back to the XLA scan solver then.  Blocks are also kept under
+    :data:`_MAX_BLOCK_ELEMS` elements, which bounds compile time.
+    ``state_io`` selects the adaptive kernel's larger footprint (see
+    :func:`fused_block_vmem_bytes`).
     """
-    avail = budget // 4 - 2 * d * d - d
-    if avail <= 0:
-        return None
-    per_col = 14 if state_io else 9
-    rows = 4 if state_io else 2
-    bk = avail // (per_col * d + rows)
-    if bk < 1:
-        return None
-    if bk >= k:
+    widest = max(_LANES, _MAX_BLOCK_ELEMS // d // _LANES * _LANES)
+    if k <= widest and fused_block_vmem_bytes(d, k, state_io) <= budget:
         return k
-    # round down to a full-lane multiple when possible; below 128 the
-    # budget forces lane-padded tiles either way, so settle for the
-    # f32 sublane granularity
-    if bk >= 128:
-        bk = (bk // 128) * 128
-    elif bk >= 8:
-        bk = (bk // 8) * 8
-    return bk
+    for bk in range(min(widest, (k - 1) // _LANES * _LANES), 0, -_LANES):
+        if fused_block_vmem_bytes(d, bk, state_io) <= budget:
+            return bk
+    return None
 
 
-def _matmul(m, x):
+def _matmul(m, x, contract: int = 1):
+    """``m @ x`` (``contract=1``) or ``m.T @ x`` (``contract=0``) in full
+    f32 -- Mosaic's fp32 contract precision, as the scan solver."""
     return jax.lax.dot_general(
-        m, x, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        m, x, (((contract,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )
 
 
@@ -186,10 +240,22 @@ def _shrink(x, t):
     return jnp.sign(x) * jnp.maximum(jnp.abs(x) - t, 0.0)
 
 
-def _admm_iteration(a, q, inv, b, lam, inv_rho, alpha, z, w, u1, u2):
+def _beta_update(a_ref, q_ref, inv, b, z, w, u1, u2):
+    """``beta = Q diag(inv) Q^T (A (z + b - u1) + w - u2)`` and ``A beta``.
+
+    A and Q are read from their VMEM refs at each use.  Held as values
+    across the loop, and with an explicit ``Q.T``, Mosaic spilled copies
+    of them into O(d^2) VMEM scratch, which refused d=2048.
+    """
+    a, q = a_ref[...], q_ref[...]
+    beta = _matmul(q, inv * _matmul(q, _matmul(a, z + b - u1) + (w - u2),
+                                    contract=0))
+    return beta, _matmul(a, beta)
+
+
+def _admm_iteration(a_ref, q_ref, inv, b, lam, inv_rho, alpha, z, w, u1, u2):
     """One exact two-block ADMM iteration (identical on every path)."""
-    beta = _matmul(q, inv * _matmul(q.T, _matmul(a, z + b - u1) + (w - u2)))
-    ab = _matmul(a, beta)
+    beta, ab = _beta_update(a_ref, q_ref, inv, b, z, w, u1, u2)
     ab_r = alpha * ab + (1.0 - alpha) * (z + b)
     beta_r = alpha * beta + (1.0 - alpha) * w
     z_new = jnp.clip(ab_r - b + u1, -lam, lam)
@@ -201,9 +267,11 @@ def _admm_iteration(a, q, inv, b, lam, inv_rho, alpha, z, w, u1, u2):
 
 def _fused_admm_kernel(a_ref, q_ref, inv_ref, b_ref, lam_ref, rho_ref, out_ref,
                        *, iters: int, alpha: float):
-    """Fixed-iteration, cold-start kernel (the golden-pinned fast path)."""
-    a = a_ref[...]  # (d, d) VMEM-resident across all iterations
-    q = q_ref[...]  # (d, d) eigenvectors of A
+    """Fixed-iteration, cold-start kernel (the golden-pinned fast path).
+
+    ``a_ref`` and ``q_ref`` hold A and its eigenvectors Q, (d, d) and
+    VMEM-resident across all iterations.
+    """
     inv = inv_ref[...]  # (d, 1) 1/(eig^2 + 1)
     b = b_ref[...]  # (d, block_k) this grid step's column block
     lam = lam_ref[...]  # (1, block_k)
@@ -213,7 +281,8 @@ def _fused_admm_kernel(a_ref, q_ref, inv_ref, b_ref, lam_ref, rho_ref, out_ref,
 
     def body(_, carry):
         z, w, u1, u2 = carry
-        return _admm_iteration(a, q, inv, b, lam, inv_rho, alpha, z, w, u1, u2)
+        return _admm_iteration(a_ref, q_ref, inv, b, lam, inv_rho, alpha,
+                               z, w, u1, u2)
 
     z, w, u1, u2 = jax.lax.fori_loop(0, iters, body, (zeros, zeros, zeros, zeros))
     out_ref[...] = w
@@ -233,8 +302,6 @@ def _fused_admm_state_kernel(a_ref, q_ref, inv_ref, b_ref, lam_ref, rho_ref,
     its max scaled residual drops below ``tol`` (capped at exactly
     ``max_iters`` iterations -- the final chunk is clamped).
     """
-    a = a_ref[...]
-    q = q_ref[...]
     inv = inv_ref[...]
     b = b_ref[...]
     lam = lam_ref[...]
@@ -246,7 +313,7 @@ def _fused_admm_state_kernel(a_ref, q_ref, inv_ref, b_ref, lam_ref, rho_ref,
         def body(_, carry):
             z, w, u1, u2 = carry
             return _admm_iteration(
-                a, q, inv, b, lam, inv_rho, alpha, z, w, u1, u2)
+                a_ref, q_ref, inv, b, lam, inv_rho, alpha, z, w, u1, u2)
 
         z, w, u1, u2 = jax.lax.fori_loop(0, max_iters, body, state0)
         it = jnp.int32(max_iters)
@@ -260,7 +327,7 @@ def _fused_admm_state_kernel(a_ref, q_ref, inv_ref, b_ref, lam_ref, rho_ref,
             def body(_, c):
                 z, w, u1, u2, _, _ = c
                 zn, wn, u1n, u2n = _admm_iteration(
-                    a, q, inv, b, lam, inv_rho, alpha, z, w, u1, u2)
+                    a_ref, q_ref, inv, b, lam, inv_rho, alpha, z, w, u1, u2)
                 return zn, wn, u1n, u2n, zn - z, wn - w
 
             zeros = jnp.zeros_like(b)
@@ -269,12 +336,10 @@ def _fused_admm_state_kernel(a_ref, q_ref, inv_ref, b_ref, lam_ref, rho_ref,
             # scaled-ADMM residuals of the block, entirely in VMEM:
             # one extra beta solve (4 matmuls) per chunk -- a
             # 1/check_every relative overhead on the chunk's compute.
-            beta = _matmul(q, inv * _matmul(q.T, _matmul(a, z + b - u1)
-                                            + (w - u2)))
-            ab = _matmul(a, beta)
+            beta, ab = _beta_update(a_ref, q_ref, inv, b, z, w, u1, u2)
             r_pri = jnp.maximum(jnp.max(jnp.abs(ab - z - b)),
                                 jnp.max(jnp.abs(beta - w)))
-            dual_col = jnp.max(jnp.abs(_matmul(a, dz) + dw), axis=0,
+            dual_col = jnp.max(jnp.abs(_matmul(a_ref[...], dz) + dw), axis=0,
                                keepdims=True)  # (1, block_k)
             s_dual = jnp.max(rho * dual_col)
             return it + n, z, w, u1, u2, jnp.maximum(r_pri, s_dual)
@@ -291,7 +356,7 @@ def _fused_admm_state_kernel(a_ref, q_ref, inv_ref, b_ref, lam_ref, rho_ref,
     z_ref[...] = z
     u1_ref[...] = u1
     u2_ref[...] = u2
-    it_ref[...] = jnp.full((1, 1), it, jnp.int32)
+    it_ref[...] = jnp.full(it_ref.shape, it, jnp.int32)
 
 
 def _pad_cols(x: jnp.ndarray, pad: int, value: float = 0.0) -> jnp.ndarray:
@@ -382,6 +447,9 @@ def dantzig_fused_pallas(
 
     a2 = a.astype(jnp.float32)
     q2 = q.astype(jnp.float32)
+    # the blocking model plans for part of VMEM; Mosaic may use all of it
+    compiler_params = None if interpret else pltpu.CompilerParams(
+        vmem_limit_bytes=chip_vmem_bytes())
     shared_specs = [
         pl.BlockSpec((d, d), lambda i: (0, 0)),
         pl.BlockSpec((d, d), lambda i: (0, 0)),
@@ -403,6 +471,8 @@ def dantzig_fused_pallas(
             out_specs=pl.BlockSpec((d, block_k), lambda i: (0, i)),
             out_shape=jax.ShapeDtypeStruct((d, k_pad), jnp.float32),
             interpret=interpret,
+            compiler_params=compiler_params,
+            name=FIXED_KERNEL,
         )(a2, q2, inv2, b2, lam2, rho2)
         return out[:, :k] if pad else out
 
@@ -418,16 +488,22 @@ def dantzig_fused_pallas(
         _fused_admm_state_kernel, max_iters=iters, alpha=alpha,
         tol=tol, check_every=check_every)
     col_spec = pl.BlockSpec((d, block_k), lambda i: (0, i))
+    # each block's iteration count fills one full (1, 128) lane tile:
+    # Mosaic refuses a (1, 1) block of a (1, num_blocks) array
+    it_spec = pl.BlockSpec((1, _LANES), lambda i: (0, i))
     w, z, u1, u2, it = pl.pallas_call(
         kernel,
         grid=(num_blocks,),
         in_specs=shared_specs + [col_spec] * 4,
-        out_specs=[col_spec] * 4 + [pl.BlockSpec((1, 1), lambda i: (0, i))],
+        out_specs=[col_spec] * 4 + [it_spec],
         out_shape=[jax.ShapeDtypeStruct((d, k_pad), jnp.float32)] * 4
-        + [jax.ShapeDtypeStruct((1, num_blocks), jnp.int32)],
+        + [jax.ShapeDtypeStruct((1, num_blocks * _LANES), jnp.int32)],
         interpret=interpret,
+        compiler_params=compiler_params,
+        name=STATE_KERNEL,
     )(a2, q2, inv2, b2, lam2, rho2, *state)
     if pad:
         w, z, u1, u2 = (x[:, :k] for x in (w, z, u1, u2))
-    result = FusedSolveResult(w, AdmmState(z, w, u1, u2), it.reshape(-1))
+    it = it.reshape(num_blocks, _LANES)[:, 0]
+    result = FusedSolveResult(w, AdmmState(z, w, u1, u2), it)
     return result if return_info else result.beta

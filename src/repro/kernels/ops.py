@@ -120,6 +120,10 @@ def dantzig_fused(a, b, lam, *, iters=500, rho=1.0, alpha=1.7,
             block_k = b.shape[1]  # interpreter has no VMEM limit
     elif not interpret:
         bk = max(1, min(block_k, b.shape[1]))
+        if bk != b.shape[1] and bk % 128:
+            raise ValueError(
+                f"dantzig_fused: block_k={block_k} must be a multiple of "
+                f"128 or the whole batch ({b.shape[1]}) for Mosaic")
         if fused_block_vmem_bytes(d, bk, state_io=state_io) > vmem_budget:
             raise ValueError(
                 f"dantzig_fused: block_k={block_k} at d={d} exceeds "

@@ -1,31 +1,27 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
-# --- everything below may import jax (device count is now locked) ---------
-import argparse  # noqa: E402
-import json  # noqa: E402
-import re  # noqa: E402
-import time  # noqa: E402
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro.core.dantzig import DantzigConfig  # noqa: E402
-from repro.core.distributed import distributed_slda_shardmap  # noqa: E402
-from repro.launch import mesh as mesh_lib  # noqa: E402
-
 """Dry-run of the paper's technique on the production mesh.
 
 Lowers Algorithm 1 (the one-shot distributed sparse-LDA estimator) via
 shard_map on the 16x16 / 2x16x16 meshes with abstract inputs and
-extracts the roofline terms.  This is the baseline/optimized pair
-tracked in EXPERIMENTS.md SSPerf-A.
+extracts the roofline terms.
 
 Machines = data slices (16 per pod x pods); CLIME columns sharded over
-the 16-wide model axis.
+the 16-wide model axis.  ``main()`` forces 512 host devices, so run it
+as its own process: ``python -m repro.launch.dryrun_slda``.
 """
+
+import argparse
+import json
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from repro.core.dantzig import DantzigConfig
+from repro.core.distributed import distributed_slda_shardmap
+from repro.launch import mesh as mesh_lib
 
 # TPU v5e constants (target hardware; container runtime is CPU)
 PEAK_FLOPS = 197e12  # bf16 per chip
@@ -180,6 +176,8 @@ def run_one(d: int, n_per_machine: int, multi_pod: bool, max_iters: int,
 
 
 def main():
+    # must precede JAX's backend start-up, which happens on first use
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--d", type=int, default=256)
     ap.add_argument("--n", type=int, default=4096)

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,7 @@ from repro.core.streaming import (
     ServingRuntime,
     corrupt_batch_arrays,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.stats.synthetic import (
     make_mc_problem,
     make_problem,
@@ -71,7 +73,17 @@ def _mc_stream(key, problem, classes, n_seed, n_batch, n_query):
             lambda arrs: mc_suff_stats(arrs[0], arrs[1], classes))
 
 
-def main() -> None:
+class ServeReport(NamedTuple):
+    """What one serving run saw, tick by tick."""
+
+    served: int  # queries classified
+    statuses: list  # the runtime's status at each tick
+    all_finite: bool  # every served score was finite
+    ladder_log: list  # every refit attempt, the initial fit included
+    accuracy: float  # mean per-tick accuracy
+
+
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--d", type=int, default=60)
     ap.add_argument("--classes", type=int, default=2)
@@ -98,8 +110,11 @@ def main() -> None:
     ap.add_argument("--unprotected", action="store_true",
                     help="fragile baseline: no screening/verdict/staleness")
     ap.add_argument("--ckpt-dir", default=None)
-    args = ap.parse_args()
+    return ap
 
+
+def serve(args: argparse.Namespace) -> ServeReport:
+    """Drive one serving run as configured by :func:`build_parser`."""
     if args.smoke:
         args.d, args.ticks, args.batch, args.ingest = 28, 10, 128, 40
 
@@ -134,7 +149,7 @@ def main() -> None:
                              cfg=cfg, staleness_bound=args.staleness_bound)
 
     accs, statuses, quarantined, t_classify, served = [], [], 0, 0.0, 0
-    ref_accs = []
+    ref_accs, all_finite = [], True
     for t in range(args.ticks):
         key, kt = jax.random.split(key)
         raw, z, lab = tick_fn(kt)
@@ -144,6 +159,7 @@ def main() -> None:
         t_classify += time.perf_counter() - t0
         served += int(z.shape[0])
         finite = bool(np.isfinite(np.asarray(scores)).all())
+        all_finite = all_finite and finite
         accs.append(float(jnp.mean(pred == lab)))
         statuses.append(rt.status)
         if args.chaos:
@@ -196,6 +212,14 @@ def main() -> None:
             raise SystemExit("restore parity violated: same slot version, "
                              "different predictions")
         print(f"checkpoint restore OK (version {int(restored.slot.version)})")
+    return ServeReport(served, statuses, all_finite, list(rt.ladder_log),
+                       float(np.mean(accs)))
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    enable_compile_cache()
+    serve(args)
 
 
 if __name__ == "__main__":

@@ -4,11 +4,14 @@ Run from the repo root:
 
     PYTHONPATH=src python tests/golden/generate_binary_golden.py
 
-The .npz this writes was produced at commit 38e71e8 (BEFORE the
-head-parameterized pipeline refactor) so the parity tests in
-``tests/test_pipeline_parity.py`` pin the refactor against the exact
-pre-refactor numbers.  Re-running it on a later commit re-bases the pin
-to the current implementation -- only do that deliberately.
+The .npz this writes was first produced before the head-parameterized
+pipeline refactor, so the parity tests in ``tests/test_pipeline_parity.py``
+pinned the refactor against the exact pre-refactor numbers.  It was
+re-pinned on JAX 0.9: its default ``jax_threefry_partitionable=True``
+draws other synthetic samples from the same keys.  The pins are always
+computed on the CPU: the plain f32 path, with the Pallas kernels in the
+interpreter.  Re-running it re-bases the pin to the current
+implementation -- only do that deliberately.
 
 The shard_map case runs in a subprocess with 2 forced host devices so
 the main process keeps its default device count.
@@ -84,6 +87,7 @@ def main():
         os.environ,
         PYTHONPATH=os.path.join(REPO, "src"),
         GOLDEN_OUT=OUT,
+        JAX_PLATFORMS="cpu",  # the plain f32 path, kernels interpreted
     )
     res = subprocess.run([sys.executable, "-c", BODY], env=env, cwd=REPO,
                          capture_output=True, text=True, timeout=900)

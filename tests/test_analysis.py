@@ -29,7 +29,6 @@ from repro.analysis import (
 from repro.analysis import cases as cases_mod
 from repro.analysis import imports as import_rules
 from repro.analysis import lint, registry
-from repro.core.distributed import _shard_map
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -60,9 +59,10 @@ def test_count_eqns_recurses_into_scan_while_cond_pjit():
     # scan body traces once (not per iteration); cond holds one eigh in
     # one branch; while body one; the inner jit one
     assert count_eqns(jaxpr, "eigh") == 4
-    joined = ["/".join(s.path) for s in find_eqns(jaxpr, "eigh")]
-    for enclosing in ("scan", "while", "cond", "pjit"):
-        assert any(enclosing in j for j in joined), (enclosing, joined)
+    outermost = [s.path[0] for s in find_eqns(jaxpr, "eigh")]
+    # jax names the pjit primitive "jit"
+    for enclosing in ("scan", "while", "cond", "jit"):
+        assert enclosing in outermost, (enclosing, outermost)
 
 
 def test_count_eqns_accepts_closed_and_raw_jaxpr():
@@ -82,8 +82,8 @@ def test_count_eqns_out_shape_matcher():
 
 def test_count_eqns_recurses_into_shard_map():
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    fn = _shard_map(lambda x: jax.lax.psum(x, "data"), mesh,
-                    (P("data"),), P())
+    fn = jax.shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
+                       in_specs=(P("data"),), out_specs=P(), check_vma=False)
     jaxpr = jax.make_jaxpr(fn)(jnp.ones((4,)))
     sites = find_eqns(jaxpr, "psum")
     assert len(sites) == 1
@@ -125,7 +125,8 @@ def test_budget_param_resolution_and_missing_param():
 
 def _trace_shard(body, *args):
     mesh = jax.make_mesh((1, 1), ("data", "model"))
-    fn = _shard_map(body, mesh, tuple(P() for _ in args), P())
+    fn = jax.shard_map(body, mesh=mesh, in_specs=tuple(P() for _ in args),
+                       out_specs=P(), check_vma=False)
     return jax.make_jaxpr(fn)(*args)
 
 

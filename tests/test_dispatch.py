@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core.dantzig import DantzigConfig, solve_dantzig, solve_dantzig_scan
 from repro.core.solver_dispatch import (
@@ -13,6 +14,8 @@ from repro.core.solver_dispatch import (
     fused_block_vmem_bytes,
 )
 from repro.core import solver_dispatch
+from repro.kernels import dantzig_fused
+from repro.kernels.dantzig_fused import CHIP_VMEM_BYTES, chip_vmem_bytes
 from repro.stats.synthetic import ar1_covariance
 
 
@@ -28,14 +31,14 @@ def test_fused_single_block_for_small_shapes():
 
 
 def test_fused_blocked_for_wide_batches():
-    choice = select_solver(DantzigConfig(fused=True), 768, 512)
+    choice = select_solver(DantzigConfig(fused=True), 768, 4096)
     assert choice.kind == "fused_blocked"
-    assert 0 < choice.block_k < 512
+    assert 0 < choice.block_k < 4096 and choice.block_k % 128 == 0
     assert fused_block_vmem_bytes(768, choice.block_k) <= DEFAULT_VMEM_BUDGET
 
 
 def test_scan_fallback_when_operands_exceed_vmem():
-    # A + Q alone are 2 * 4096^2 * 4 B = 128 MiB >> VMEM
+    # A + Q alone are 2 * 4096^2 * 4 B = 128 MiB, double-buffered 256 MiB
     assert select_solver(DantzigConfig(fused=True), 4096, 8).kind == "scan"
 
 
@@ -84,73 +87,84 @@ def test_scan_accepts_warm_rho_seed():
     np.testing.assert_allclose(np.asarray(base), np.asarray(warm), atol=5e-4)
 
 
-def test_backend_budgets_drive_selection():
+class _FakeChip:
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+def test_backend_budgets_drive_selection(monkeypatch):
     """The backend parameter is live: it resolves the fast-memory budget."""
-    # cpu mirrors the TPU budget so interpreter-validated shapes pick
-    # the path they will pick on TPU
-    assert backend_vmem_budget("cpu") == backend_vmem_budget("tpu") \
-        == DEFAULT_VMEM_BUDGET
+    # cpu mirrors the target chip so interpreter-validated shapes pick
+    # the path they will pick on the chip
+    assert backend_vmem_budget("cpu") == DEFAULT_VMEM_BUDGET
     # the active backend is the default (this suite runs on cpu)
     assert backend_vmem_budget() == backend_vmem_budget(
         jax.default_backend())
+    # tpu reads the attached chip's device_kind ...
+    monkeypatch.setattr(dantzig_fused.jax, "devices",
+                        lambda *_: [_FakeChip("TPU v5 lite")])
+    assert backend_vmem_budget("tpu") == DEFAULT_VMEM_BUDGET
     cfg = DantzigConfig(fused=True)
-    # (256, 64) fits one block under the TPU budget...
     assert select_solver(cfg, 256, 64, backend="tpu").kind == "fused"
-    # ...but A + Q at d=256 alone bust a GPU shared-memory-sized
-    # budget, so the same shape falls back to scan there
-    assert backend_vmem_budget("gpu") < DEFAULT_VMEM_BUDGET
-    assert select_solver(cfg, 256, 64, backend="gpu").kind == "scan"
-    # an unknown backend gets the conservative default
-    assert backend_vmem_budget("wasm") == DEFAULT_VMEM_BUDGET
+    # ... and a chip with no VMEM figure is an error, not a default
+    monkeypatch.setattr(dantzig_fused.jax, "devices",
+                        lambda *_: [_FakeChip("TPU v2")])
+    with pytest.raises(ValueError, match="TPU v2"):
+        backend_vmem_budget("tpu")
+    # so is a backend the model does not know
+    with pytest.raises(ValueError, match="wasm"):
+        backend_vmem_budget("wasm")
 
 
 def test_backend_budget_exact_values():
     """The budget constants are part of the dispatch contract.
 
-    TPU and CPU share the 12 MiB VMEM model (interpreter-validated
-    shapes must pick the path they will pick on TPU); GPU gets a
-    shared-memory-sized 192 KiB.  A change here silently reroutes
-    every shape's scan/fused/fused_blocked decision, so the exact
-    numbers are pinned, not just their ordering.
+    v5e has 128 MiB of VMEM per core (its compiler's own figure); the
+    blocking model plans for 3/4 of it and CPU mirrors the chip.  A
+    change here silently reroutes every shape's scan/fused/
+    fused_blocked decision, so the exact numbers are pinned, not just
+    their ordering.
     """
-    assert backend_vmem_budget("tpu") == 12 * 2**20
-    assert backend_vmem_budget("cpu") == 12 * 2**20
-    assert backend_vmem_budget("gpu") == 192 * 2**10
+    assert CHIP_VMEM_BYTES["TPU v5 lite"] == 128 * 2**20
+    assert chip_vmem_bytes("cpu") == 128 * 2**20
+    assert backend_vmem_budget("cpu") == DEFAULT_VMEM_BUDGET == 96 * 2**20
 
 
 def test_gpu_scan_fallback_boundary():
-    """GPU fuses small d, tiles mid d, and bails exactly where A+Q bust 192 KiB."""
-    cfg = DantzigConfig(fused=True)
-    # d=64: A + Q = 32 KiB, well inside the 192 KiB budget
-    choice = select_solver(cfg, 64, 8, backend="gpu")
-    assert choice == SolverChoice("fused", 8)
-    assert fused_block_vmem_bytes(64, 8) <= backend_vmem_budget("gpu")
-    # d=128: A + Q = 128 KiB leave room for a few columns -> tiled,
-    # rounded down to the f32 sublane granularity
-    assert select_solver(cfg, 128, 64, backend="gpu") == \
-        SolverChoice("fused_blocked", 8)
-    # d=160: A + Q alone exceed the budget -- not even one column fits,
+    """GPU has no VMEM model and raises; under a tight explicit budget the
+    dispatch fuses small d, tiles mid d in 128-column blocks, and bails
+    where A + Q alone bust the budget."""
+    with pytest.raises(ValueError, match="gpu"):
+        select_solver(DantzigConfig(fused=True), 64, 8, backend="gpu")
+    budget = fused_block_vmem_bytes(256, 128)
+    cfg = DantzigConfig(fused=True, vmem_budget=budget)
+    # d=256, 64 columns: the whole batch fits one block
+    assert select_solver(cfg, 256, 64) == SolverChoice("fused", 64)
+    # 512 columns: tiled at the 128-lane granularity
+    assert select_solver(cfg, 256, 512) == SolverChoice("fused_blocked", 128)
+    # d=512: A + Q alone exceed the budget -- not even one column fits,
     # and the fallback ignores any explicit block_k override
-    assert select_solver(cfg, 160, 1, backend="gpu").kind == "scan"
-    assert select_solver(DantzigConfig(fused=True, block_k=1),
-                         160, 1, backend="gpu").kind == "scan"
+    assert select_solver(cfg, 512, 1).kind == "scan"
+    assert select_solver(cfg._replace(block_k=1), 512, 1).kind == "scan"
 
 
 def test_state_io_footprint_drives_gpu_selection():
-    """The adaptive kernel's larger footprint shrinks the GPU block.
+    """The adaptive kernel's larger footprint shrinks the block.
 
     ``cfg.tol`` routes to the adaptive kernel, whose streamed-in/out
-    ADMM state costs 14 (d, block_k) arrays instead of 9 -- on the
-    tight GPU budget that is visible as a smaller block for the SAME
-    shape.  An explicit ``state_io`` overrides the cfg derivation.
+    ADMM state costs eight more (d, block_k) buffers -- under a tight
+    budget that is visible as a smaller block for the SAME shape.  An
+    explicit ``state_io`` overrides the cfg derivation.
     """
-    d, k = 144, 16
-    fixed = select_solver(DantzigConfig(fused=True), d, k, backend="gpu")
-    adaptive = select_solver(DantzigConfig(fused=True, tol=1e-4), d, k,
-                             backend="gpu")
-    assert fixed.kind == adaptive.kind == "fused_blocked"
+    d, k = 256, 1024
+    budget = fused_block_vmem_bytes(d, 512)
+    fixed = select_solver(DantzigConfig(fused=True, vmem_budget=budget), d, k)
+    adaptive = select_solver(
+        DantzigConfig(fused=True, tol=1e-4, vmem_budget=budget), d, k)
+    assert fixed == SolverChoice("fused_blocked", 512)
+    assert adaptive.kind == "fused_blocked"
     assert adaptive.block_k < fixed.block_k
-    assert select_solver(DantzigConfig(fused=True), d, k, backend="gpu",
+    assert select_solver(DantzigConfig(fused=True, vmem_budget=budget), d, k,
                          state_io=True) == adaptive
 
 
@@ -160,12 +174,11 @@ def test_cfg_vmem_budget_overrides_backend():
     # every backend
     tiny = DantzigConfig(fused=True, vmem_budget=100_000)
     assert select_solver(tiny, 256, 64).kind == "scan"
-    assert select_solver(tiny, 256, 64, backend="tpu").kind == "scan"
+    assert select_solver(tiny, 256, 64, backend="cpu").kind == "scan"
     # a budget big enough for one block keeps the whole batch fused
     # even where the backend budget would have tiled or bailed
     huge = DantzigConfig(fused=True, vmem_budget=2**30)
-    assert select_solver(huge, 768, 512, backend="gpu") == \
-        SolverChoice("fused", 512)
+    assert select_solver(huge, 256, 512) == SolverChoice("fused", 512)
     # and the end-to-end solve under an explicit budget stays exact
     d = 32
     a = jnp.asarray(ar1_covariance(d, 0.6), jnp.float32)

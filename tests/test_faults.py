@@ -410,7 +410,6 @@ def test_mesh_compressed_reentry_matches_uninterrupted():
         from repro.core import rounds as rounds_core
         from repro.core.compression import Compression
         from repro.core.dantzig import DantzigConfig
-        from repro.core.distributed import _shard_map
         from repro.core.pipeline import BinaryHead
         from repro.stats import synthetic
 
@@ -423,23 +422,27 @@ def test_mesh_compressed_reentry_matches_uninterrupted():
         spec = P("data", None)
 
         def run(t_rounds, resume_from=None, ef_residual=None):
+            # the resumed aggregate and residual enter as operands: a
+            # shard_map body may not close over an array sharded on the
+            # mesh's (explicit) axes
             extra, specs = (), [spec, spec]
-            if ef_residual is not None:
-                extra = (ef_residual,)
-                specs.append(P("data", None, None))
+            if resume_from is not None:
+                extra = (resume_from, ef_residual)
+                specs += [P(), P("data", None, None)]
 
             def shard_fn(x, y, *rest):
                 bar, _, resid = rounds_core.worker_rounds(
                     BinaryHead(), x, y, lam=0.3, lam_prime=0.3,
                     rounds=t_rounds, cfg=cfg, model_axis="model",
                     model_axis_size=4, compression=comp,
-                    resume_from=resume_from,
-                    ef_residual=rest[0][0] if rest else None,
+                    resume_from=rest[0] if rest else None,
+                    ef_residual=rest[1][0] if rest else None,
                     return_ef_residual=True)
                 return bar, resid[None]
 
-            fn = _shard_map(shard_fn, mesh, tuple(specs),
-                            (P(), P("data", None, None)))
+            fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=tuple(specs),
+                               out_specs=(P(), P("data", None, None)),
+                               check_vma=False)
             return fn(xs.reshape(-1, d), ys.reshape(-1, d), *extra)
 
         full, _ = run(3)

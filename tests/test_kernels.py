@@ -167,18 +167,19 @@ def test_fused_explicit_blocking_with_tail_is_exact():
 
 
 def test_fused_blocked_past_single_block_vmem():
-    """A shape whose single-block footprint exceeds 16 MB still matches
-    the scan solver once the dispatch tiles it over the grid."""
-    d, k, iters = 768, 512, 25
-    assert fused_block_vmem_bytes(d, k) > 16 * 10**6
-    bk = pick_block_k(d, k)
-    assert bk is not None and bk < k  # must be tiled
-    assert fused_block_vmem_bytes(d, bk) <= 12 * 2**20
-    choice = select_solver(DantzigConfig(fused=True), d, k)
+    """A shape whose single-block footprint exceeds the budget still
+    matches the scan solver once the dispatch tiles it over the grid in
+    128-column blocks (the only width Mosaic accepts below the batch)."""
+    d, k, iters = 256, 384, 25
+    budget = fused_block_vmem_bytes(d, 128)
+    assert fused_block_vmem_bytes(d, k) > budget
+    bk = pick_block_k(d, k, budget)
+    assert bk == 128  # must be tiled, lane-aligned
+    choice = select_solver(DantzigConfig(fused=True, vmem_budget=budget), d, k)
     assert choice.kind == "fused_blocked" and choice.block_k == bk
     a = jnp.asarray(ar1_covariance(d, 0.5), jnp.float32)
     b = jax.random.normal(jax.random.PRNGKey(6), (d, k)) * 0.3
-    out_f = ops.dantzig_fused(a, b, 0.15, iters=iters)
+    out_f = ops.dantzig_fused(a, b, 0.15, iters=iters, vmem_budget=budget)
     out_s = _scan_reference(a, b, 0.15, iters)
     np.testing.assert_allclose(np.asarray(out_f), np.asarray(out_s), atol=1e-4)
 
@@ -244,6 +245,18 @@ def test_fused_blocked_trace_conforms_to_vmem_model():
     violations = VmemConformance(budget=1024).check(jaxpr)
     assert violations, "1 KiB budget must trip the conformance contract"
     assert any("pallas_call" in site for v in violations for site in v.sites)
+
+
+def test_vmem_conformance_flags_an_unreadable_launch():
+    """A fused launch whose block mappings cannot be read is itself a
+    violation: the contract never passes by skipping it."""
+    from types import SimpleNamespace as NS
+
+    launch = NS(primitive=NS(name="pallas_call"),
+                params={"name": "fused_admm"}, outvars=[])
+    violations = VmemConformance().check(NS(eqns=[launch]))
+    assert len(violations) == 1
+    assert "could not read" in violations[0].message
 
 
 def test_tol_mode_state_kernel_trace_conforms_to_vmem_model():

@@ -5,8 +5,9 @@ Two kinds of pins:
   * numeric -- every binary public API (``debiased_local_estimator``,
     ``simulated_distributed_slda`` & friends, ``distributed_slda_shardmap``
     with remainder columns) must reproduce the PRE-refactor outputs
-    stored in ``tests/golden/binary_prerefactor.npz`` (generated at
-    commit 38e71e8 by ``tests/golden/generate_binary_golden.py``);
+    stored in ``tests/golden/binary_prerefactor.npz`` (written by
+    ``tests/golden/generate_binary_golden.py``; re-pinned on JAX 0.9,
+    whose partitionable threefry default changed the synthetic draws);
   * structural -- exactly one implementation of the worker debias
     schedule remains: slda / distributed / multiclass call into
     ``core/pipeline.py``, and no module but the dispatch layer imports

@@ -277,15 +277,21 @@ def test_rounds_warm_reentry_fewer_iterations():
     cfg = DantzigConfig(max_iters=800, tol=2e-4, check_every=25)
     p = synthetic.make_problem(d=40, n_signal=5, rho=0.6)
     xs, ys = synthetic.sample_machines(jax.random.PRNGKey(6), p, 3, 150, 150)
+    # at lam=0.2 one machine's direction solve of this draw stalls above
+    # tol (still at the cap after 6000 iterations); at 0.25 all converge
+    lam = 0.25
     cold_bar, cold = rounds_core.simulate_multi_round(
-        BinaryHead(), (xs, ys), lam=0.2, lam_prime=0.2, rounds=2, cfg=cfg,
+        BinaryHead(), (xs, ys), lam=lam, lam_prime=0.2, rounds=2, cfg=cfg,
         collect_info=True)
     assert cold.iters_beta is not None and cold.iters_theta is not None
+    assert int(np.max(cold.iters_beta)) < 800, \
+        "cold direction solves must converge below the cap"
+    assert int(np.max(cold.iters_theta)) < 800, \
+        "cold CLIME solves must converge below the cap"
     cold_total = (int(np.max(cold.iters_beta))
                   + int(np.max(cold.iters_theta)))
-    assert cold_total < 2 * 800, "cold solves must converge below the cap"
     warm_bar, warm = rounds_core.simulate_multi_round(
-        BinaryHead(), (xs, ys), lam=0.2, lam_prime=0.2, rounds=2, cfg=cfg,
+        BinaryHead(), (xs, ys), lam=lam, lam_prime=0.2, rounds=2, cfg=cfg,
         collect_info=True,
         rho_beta=cold.rho_beta, rho_theta=cold.rho_theta,
         state_beta=cold.state_beta, state_theta=cold.state_theta)

@@ -239,7 +239,9 @@ def test_lambda_selection_helpers():
     lams = jnp.asarray([1e-5, 0.1, 0.2, 0.3, 0.4])
     cfg = DantzigConfig(max_iters=300, adapt_rho=False, fused=True)
     res = rpath.solve_dantzig_path(a, b, lams, cfg)
-    tol = 1e-4
+    # 300 iterations bring lam 0.2 and 0.3 to a violation of 2-4e-4 and
+    # leave 1e-5, 0.1 and 0.4 above 1e-3: tol falls in that gap
+    tol = 5e-4
     feasible = [i for i in range(L) if float(res.kkt[i]) <= tol]
     assert feasible and len(feasible) < L, res.kkt  # tol splits the grid
     idx = int(rpath.select_by_kkt(res, tol=tol))
