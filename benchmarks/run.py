@@ -1,9 +1,9 @@
 """Benchmark runner: ``PYTHONPATH=src python -m benchmarks.run``.
 
 One benchmark per paper table/figure (quick CI-sized grids by default;
-pass --paper for the published experiment sizes) plus the roofline
-aggregation over the dry-run artifacts.  Each module asserts the
-paper's qualitative claims, so a green run IS the reproduction check.
+pass --paper for the published experiment sizes).  Each module asserts
+the paper's qualitative claims, so a green run IS the reproduction
+check.
 """
 
 from __future__ import annotations
@@ -24,7 +24,6 @@ from benchmarks import (
     fused_solver,
     lambda_path,
     multi_round,
-    roofline,
     serving,
     table1_speedup,
     table2_real,
@@ -52,7 +51,6 @@ BENCHES = [
      fault_rounds.main),
     ("serving (classify hot path + streaming refit under faults)",
      serving.main),
-    ("roofline (dry-run aggregation)", roofline.main),
 ]
 
 

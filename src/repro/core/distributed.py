@@ -188,7 +188,8 @@ def distributed_slda_shardmap(
             model_axis=model_axis, model_axis_size=model_size,
             comm=worker_comm, faults=row,
         )
-        return slda.hard_threshold(beta_bar[:, 0], t)
+        with jax.named_scope("slda.aggregate"):
+            return slda.hard_threshold(beta_bar[:, 0], t)
 
     fn = jax.shard_map(shard_fn, mesh=mesh,
                        in_specs=(in_spec, in_spec) + plan_specs,
@@ -296,9 +297,10 @@ def distributed_mc_slda_shardmap(
             comm=worker_comm, faults=row,
         )
         means = ws.stats.aux.means
-        for ax in data_axes:
-            means = jax.lax.pmean(means, ax)
-        return slda.hard_threshold(beta_bar, t), means
+        with jax.named_scope("slda.aggregate"):
+            for ax in data_axes:
+                means = jax.lax.pmean(means, ax)
+            return slda.hard_threshold(beta_bar, t), means
 
     fn = jax.shard_map(
         shard_fn, mesh=mesh,
@@ -393,10 +395,11 @@ def simulated_distributed_slda(
     comm: CommPlan | None = None,
 ) -> jnp.ndarray:
     """xs: (m, n1, d), ys: (m, n2, d) -> aggregated beta_bar (d,)."""
-    return slda.hard_threshold(
-        simulated_debiased_mean(xs, ys, lam, lam_prime, cfg, rounds,
-                                compression, faults, staleness,
-                                aggregation, comm), t)
+    beta_bar = simulated_debiased_mean(xs, ys, lam, lam_prime, cfg, rounds,
+                                       compression, faults, staleness,
+                                       aggregation, comm)
+    with jax.named_scope("slda.aggregate"):
+        return slda.hard_threshold(beta_bar, t)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))

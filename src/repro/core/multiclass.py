@@ -193,7 +193,9 @@ def simulated_distributed_mc_slda(
         lam=lam, lam_prime=lam_prime, rounds=rounds, cfg=cfg,
         comm=comm, compression=compression, faults=faults,
         staleness=staleness, aggregation=aggregation)
-    return hard_threshold(beta_bar, t), jnp.mean(ws.stats.aux.means, axis=0)
+    with jax.named_scope("slda.aggregate"):
+        return (hard_threshold(beta_bar, t),
+                jnp.mean(ws.stats.aux.means, axis=0))
 
 
 @functools.partial(jax.jit, static_argnames=("num_classes", "cfg"))

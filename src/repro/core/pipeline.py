@@ -285,7 +285,8 @@ def worker_solves(
             "device; the model-axis-sharded path would need an extra "
             "(d, d) gather to pair theta_ij with theta_ji (eq. 3.3). "
             "Run with model_axis=None to symmetrize.")
-    hs = head.stats(*data)
+    with jax.named_scope("slda.stats"):
+        hs = head.stats(*data)
     return solves_from_stats(
         hs, lam=lam, lam_prime=lam_prime, cfg=cfg, model_axis=model_axis,
         model_axis_size=model_axis_size, rho_beta=rho_beta,
@@ -318,37 +319,46 @@ def solves_from_stats(
     """
     # ONE eigendecomposition per worker: the direction solve and every
     # CLIME column share this factor (it is rho- and lam-independent).
-    factor = spectral_factor(hs.sigma)
+    with jax.named_scope("slda.spectral"):
+        factor = spectral_factor(hs.sigma)
     d = hs.rhs.shape[0]
-    if model_axis is None:
-        cols = jnp.arange(d)
-        valid = None
-    else:
-        size = model_axis_size
-        idx = jax.lax.axis_index(model_axis)
-        cols_per = -(-d // size)  # ceil: pad d to a multiple of size
-        cols = idx * cols_per + jnp.arange(cols_per)
-        valid = cols < d
-        cols = jnp.minimum(cols, d - 1)
+    with jax.named_scope("slda.clime"):
+        if model_axis is None:
+            cols = jnp.arange(d)
+            valid = None
+        else:
+            size = model_axis_size
+            idx = jax.lax.axis_index(model_axis)
+            cols_per = -(-d // size)  # ceil: pad d to a multiple of size
+            cols = idx * cols_per + jnp.arange(cols_per)
+            valid = cols < d
+            cols = jnp.minimum(cols, d - 1)
     if full:
-        dir_res = solve_dantzig_full(factor, hs.rhs, lam, cfg, rho=rho_beta,
-                                     state=state_beta)
-        theta_res = solve_clime_columns_full(
-            factor, cols, lam_prime, cfg, rho=rho_theta, state=state_theta)
+        with jax.named_scope("slda.direction"):
+            dir_res = solve_dantzig_full(factor, hs.rhs, lam, cfg,
+                                         rho=rho_beta, state=state_beta)
+        with jax.named_scope("slda.clime"):
+            theta_res = solve_clime_columns_full(
+                factor, cols, lam_prime, cfg, rho=rho_theta,
+                state=state_theta)
         beta_hat, theta = dir_res.beta, theta_res.beta
         carries = dict(
             rho_beta=dir_res.rho, rho_theta=theta_res.rho,
             state_beta=dir_res.state, state_theta=theta_res.state,
             iters_beta=dir_res.iters, iters_theta=theta_res.iters)
     else:
-        beta_hat = solve_dantzig(factor, hs.rhs, lam, cfg, rho=rho_beta,
-                                 state=state_beta)
-        theta = solve_clime_columns(
-            factor, cols, lam_prime, cfg, rho=rho_theta, state=state_theta)
+        with jax.named_scope("slda.direction"):
+            beta_hat = solve_dantzig(factor, hs.rhs, lam, cfg, rho=rho_beta,
+                                     state=state_beta)
+        with jax.named_scope("slda.clime"):
+            theta = solve_clime_columns(
+                factor, cols, lam_prime, cfg, rho=rho_theta,
+                state=state_theta)
         carries = dict(rho_beta=None, rho_theta=None, state_beta=None,
                        state_theta=None, iters_beta=None, iters_theta=None)
     if symmetrize:
-        theta = symmetrize_min(theta)
+        with jax.named_scope("slda.clime"):
+            theta = symmetrize_min(theta)
     return WorkerSolves(stats=hs, beta_hat=beta_hat, theta=theta,
                         valid=valid, factor=factor, **carries)
 

@@ -304,7 +304,8 @@ def _refinement_rounds(
         a = history[-1]
         if faulted and staleness > 0 and t > 1:
             a = faults_core.select_anchor(history, stale, t, staleness)
-        beta_tilde = drv.correction(a)
+        with jax.named_scope("slda.debias"):
+            beta_tilde = drv.correction(a)
         if compression is None:
             wire = drv.corrupt(code, beta_tilde) if faulted else beta_tilde
             if not masked and not faulted:
@@ -537,10 +538,12 @@ def worker_rounds(
     )
     anchor = ws.beta_hat if resume_from is None else resume_from
     tr = Transport(comm, anchor.shape[0], anchor.shape[1], rounds)
-    anchor, tstate = _refinement_rounds(
-        _MeshRound(ws, model_axis, data_axes),
-        rounds=rounds, anchor=anchor, transport=tr, plan=faults,
-        state=TransportState(ef_residual, down_residual), ref=resume_from)
+    with jax.named_scope("slda.aggregate"):
+        anchor, tstate = _refinement_rounds(
+            _MeshRound(ws, model_axis, data_axes),
+            rounds=rounds, anchor=anchor, transport=tr, plan=faults,
+            state=TransportState(ef_residual, down_residual),
+            ref=resume_from)
     out = [anchor, ws]
     if return_ef_residual:
         out.append(tstate.up_residual)
@@ -611,10 +614,11 @@ def simulate_round_loop(
     anchor = (ws.beta_hat if resume_from is None
               else drv.broadcast(resume_from))
     tr = Transport(comm, anchor.shape[1], anchor.shape[2], rounds)
-    out, tstate = _refinement_rounds(
-        drv, rounds=rounds, anchor=anchor, transport=tr, plan=plan,
-        state=TransportState(ef_residual, down_residual),
-        ref=resume_from, return_all_rounds=return_all_rounds)
+    with jax.named_scope("slda.aggregate"):
+        out, tstate = _refinement_rounds(
+            drv, rounds=rounds, anchor=anchor, transport=tr, plan=plan,
+            state=TransportState(ef_residual, down_residual),
+            ref=resume_from, return_all_rounds=return_all_rounds)
     res = [out]
     if return_ef_residual:
         res.append(tstate.up_residual)

@@ -125,7 +125,8 @@ def multi_round_slda(
         BinaryHead(), (xs, ys), lam=lam, lam_prime=lam_prime,
         rounds=rounds, cfg=cfg, comm=comm, compression=compression,
         faults=faults, staleness=staleness, aggregation=aggregation)
-    return hard_threshold(beta_bar[:, 0], t)
+    with jax.named_scope("slda.aggregate"):
+        return hard_threshold(beta_bar[:, 0], t)
 
 
 def debiased_local_estimator_path(
