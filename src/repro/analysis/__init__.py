@@ -7,10 +7,12 @@ This package turns those invariants into machine-checked *contracts*:
 
 - :mod:`repro.analysis.walker` -- recursive jaxpr traversal (pjit / scan /
   while / cond / shard_map / pallas_call sub-jaxprs) with located eqn paths,
-  plus the shared :func:`count_eqns` counter used by the test suite.
+  plus the shared :func:`count_eqns` counter used by the test suite and
+  :func:`count_executions`, which weighs each site by its scans' trips.
 - :mod:`repro.analysis.contracts` -- the contract types: primitive-count
-  budgets, collective payload contracts, VMEM-budget conformance, and a
-  floating-point dtype policy.
+  budgets, execution counts through static scan trips, collective
+  payload contracts, VMEM-budget conformance, and a floating-point dtype
+  policy.
 - :mod:`repro.analysis.registry` -- the ``@trace_contract`` decorator that
   declares contracts next to the code they guard.
 - :mod:`repro.analysis.cases` -- representative trace shapes per entry point
@@ -24,6 +26,7 @@ from repro.analysis.contracts import (  # noqa: F401
     AxisPayloadBits,
     CollectiveContract,
     DtypePolicy,
+    ExecutionBudget,
     Param,
     PrimitiveBudget,
     Violation,
@@ -39,6 +42,7 @@ from repro.analysis.registry import (  # noqa: F401
 from repro.analysis.walker import (  # noqa: F401
     EqnSite,
     count_eqns,
+    count_executions,
     find_eqns,
     format_site,
     iter_eqns,
@@ -49,6 +53,7 @@ __all__ = [
     "CollectiveContract",
     "DtypePolicy",
     "EqnSite",
+    "ExecutionBudget",
     "Param",
     "PrimitiveBudget",
     "Violation",
@@ -56,6 +61,7 @@ __all__ = [
     "check_entry",
     "contracts_of",
     "count_eqns",
+    "count_executions",
     "find_eqns",
     "format_site",
     "iter_eqns",

@@ -547,6 +547,39 @@ def _full_raw_scan():
 
 
 # ---------------------------------------------------------------------------
+# dantzig.solve_dantzig_scan (reached through the dispatch layer)
+# ---------------------------------------------------------------------------
+
+def _scan_products_case(cfg):
+    def build():
+        factor = spectral_factor(_spd(16, seed=26))
+        b = _normal(27, (16, 4))
+
+        def fn(factor, b):
+            return solve_dantzig_full(factor, b, 0.1, cfg)
+        return fn, (factor, b)
+    return build
+
+
+# four products per iteration, plus ceil(max_iters / adapt_every)
+# residual products (none with fixed rho)
+case("dantzig.solve_dantzig_scan", "default-600-d16-k4",
+     {"products": 4 * 600 + 60, "rhs": (16, 4)},
+     )(_scan_products_case(DantzigConfig()))
+case("dantzig.solve_dantzig_scan", "tail-67-d16-k4",
+     {"products": 4 * 67 + 7, "rhs": (16, 4)},
+     )(_scan_products_case(DantzigConfig(max_iters=67)))
+case("dantzig.solve_dantzig_scan", "adapt-every-1-45-d16-k4",
+     {"products": 5 * 45, "rhs": (16, 4)},
+     )(_scan_products_case(DantzigConfig(max_iters=45, adapt_every=1)))
+case("dantzig.solve_dantzig_scan", "adapt-every-7-50-d16-k4",
+     {"products": 4 * 50 + 8, "rhs": (16, 4)},
+     )(_scan_products_case(DantzigConfig(max_iters=50, adapt_every=7)))
+case("dantzig.solve_dantzig_scan", "fixed-rho-40-d16-k4",
+     {"products": 4 * 40, "rhs": (16, 4)})(_scan_products_case(SCAN))
+
+
+# ---------------------------------------------------------------------------
 # streaming.classify_batch / streaming.refit_step (the serving runtime)
 # ---------------------------------------------------------------------------
 

@@ -110,6 +110,36 @@ class PrimitiveBudget(NamedTuple):
         return violations
 
 
+class ExecutionBudget(NamedTuple):
+    """Pin how many times one call runs a primitive: each site counted
+    once per trip of its enclosing scans (``walker.count_executions``).
+    A matching site under a ``while`` or a ``cond`` has no static trip
+    count and is a violation."""
+
+    prim: str
+    exact: IntOrParam
+    out_shape: Optional[ShapeOrParam] = None
+
+    def describe(self) -> str:
+        shape = f" @{self.out_shape}" if self.out_shape is not None else ""
+        return f"executions[{self.prim}{shape} =={self.exact}]"
+
+    def check(self, jaxpr, params=None) -> list:
+        out_shape = resolve(self.out_shape, params)
+        exact = resolve(self.exact, params)
+        try:
+            n = walker.count_executions(jaxpr, self.prim, out_shape)
+        except ValueError as err:
+            return [Violation(self.describe(), str(err))]
+        if n == exact:
+            return []
+        return [Violation(
+            self.describe(),
+            f"`{self.prim}` runs {n} times per call, expected {exact}",
+            _fmt(walker.find_eqns(jaxpr, self.prim, out_shape)),
+        )]
+
+
 def _eqn_axes(eqn) -> Tuple[str, ...]:
     """Named mesh axes a collective eqn reduces/gathers over."""
     axes = eqn.params.get("axes", None)

@@ -89,3 +89,42 @@ def find_eqns(jaxpr, prim_name: str, out_shape=None) -> list[EqnSite]:
 def count_eqns(jaxpr, prim_name: str, out_shape=None) -> int:
     """Count primitive occurrences, descending into nested jaxprs."""
     return len(find_eqns(jaxpr, prim_name, out_shape))
+
+
+def _iter_executions(jaxpr, trips: int | None = 1,
+                    path: tuple[str, ...] = ()) -> Iterator[tuple]:
+    """Like :func:`iter_eqns`, with how many times each eqn runs per
+    call: ``(site, trips)``, ``trips`` the product of the enclosing
+    scans' static lengths, or None under a ``while`` or a ``cond``,
+    whose trips the trace does not fix."""
+    jaxpr = as_jaxpr(jaxpr)
+    for eqn in jaxpr.eqns:
+        yield EqnSite(eqn, path), trips
+        name = eqn.primitive.name
+        inner = trips
+        if name == "scan" and trips is not None:
+            inner = trips * eqn.params["length"]
+        elif name in ("while", "cond"):
+            inner = None
+        for value in eqn.params.values():
+            for sub in _sub_jaxprs(value):
+                yield from _iter_executions(sub, inner, path + (name,))
+
+
+def count_executions(jaxpr, prim_name: str, out_shape=None) -> int:
+    """How many times one call runs ``prim_name``: each site counted once
+    per trip of its enclosing scans.  Raises ValueError for a site under
+    a ``while`` or a ``cond``, whose trip count is not static."""
+    want = tuple(out_shape) if out_shape is not None else None
+    total = 0
+    for site, trips in _iter_executions(jaxpr):
+        if site.eqn.primitive.name != prim_name:
+            continue
+        if want is not None and not any(
+            getattr(v.aval, "shape", None) == want for v in site.eqn.outvars
+        ):
+            continue
+        if trips is None:
+            raise ValueError(f"no static trip count at {format_site(site)}")
+        total += trips
+    return total

@@ -39,7 +39,11 @@ Extras, all fixed-shape and `lax.scan`-able:
   * over-relaxation (alpha ~ 1.7),
   * residual-balancing adaptive rho -- free here because the cached
     factor (A^2+I) does not depend on rho; only the scaled duals and
-    the shrink threshold rescale,
+    the shrink threshold rescale.  On the fixed schedule the residuals
+    (one more (d,d) product) are evaluated only on the iterations that
+    adapt rho, one in ``cfg.adapt_every``: it runs as chunks of one
+    adapting step and ``adapt_every - 1`` plain ones.  The early exit
+    below evaluates them on every iteration and masks all but those,
   * residual-gated early exit (``cfg.tol``): the fixed ``lax.scan``
     becomes a bounded ``lax.while_loop`` over ``cfg.check_every``-
     iteration chunks that stops once the batch's max scaled residual
@@ -75,6 +79,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.analysis import ExecutionBudget, Param, trace_contract
 from repro.kernels import ops as kops
 from repro.kernels.dantzig_fused import AdmmState  # noqa: F401  (re-export)
 from repro.kernels.spectral import (  # noqa: F401  (re-exported API)
@@ -170,6 +175,15 @@ def solve_dantzig(
     return solver_dispatch.solve_dantzig(a, b, lam, cfg, rho=rho)
 
 
+@trace_contract(
+    "dantzig.solve_dantzig_scan",
+    contracts=(
+        # four (d,d)x(d,k) products per iteration, and the residual's
+        # fifth only on the ceil(max_iters / adapt_every) adapting ones
+        ExecutionBudget("dot_general", exact=Param("products"),
+                        out_shape=Param("rhs")),
+    ),
+)
 @partial(jax.jit, static_argnames=("cfg", "return_rho", "return_info"))
 def solve_dantzig_scan(
     a: jnp.ndarray | SpectralFactor,
@@ -231,7 +245,12 @@ def solve_dantzig_scan(
 
     alpha = cfg.alpha
 
-    def body(state: DantzigState, i):
+    def step(state: DantzigState, adapt) -> DantzigState:
+        """One ADMM iteration.  A plain step (``adapt`` False) leaves rho
+        and the scaled duals exactly as they are.  Otherwise the step
+        also evaluates the residual-balancing statistics, one more (d,d)
+        product, and rescales rho and the duals: always for ``adapt``
+        True, and where it holds for a traced boolean ``adapt``."""
         z0, w0 = state.z, state.w
         rho = state.rho[None, :]
         beta = solve_m(_mm(a, z0 + b - state.u1) + (w0 - state.u2))
@@ -243,8 +262,8 @@ def solve_dantzig_scan(
         w = soft_threshold(beta_r + state.u2, 1.0 / rho, cfg.use_kernel)
         u1 = state.u1 + ab_r - z - b
         u2 = state.u2 + beta_r - w
-        if not cfg.adapt_rho:
-            return DantzigState(z, w, u1, u2, state.rho), None
+        if adapt is False:
+            return DantzigState(z, w, u1, u2, state.rho)
         # residual balancing (per problem in the batch)
         r_pri = jnp.sqrt(jnp.sum((ab - z - b) ** 2 + (beta - w) ** 2, axis=0))
         s_dual = state.rho * jnp.sqrt(
@@ -252,18 +271,46 @@ def solve_dantzig_scan(
         )
         up = r_pri > cfg.rho_mu * s_dual
         down = s_dual > cfg.rho_mu * r_pri
-        do_adapt = (i % cfg.adapt_every) == 0
-        scale = jnp.where(
-            do_adapt & up, cfg.rho_tau, jnp.where(do_adapt & down, 1.0 / cfg.rho_tau, 1.0)
-        )
+        if adapt is not True:
+            up, down = adapt & up, adapt & down
+        scale = jnp.where(up, cfg.rho_tau, jnp.where(down, 1.0 / cfg.rho_tau, 1.0))
         new_rho = state.rho * scale
         # scaled duals u = y/rho must rescale with rho
         u1 = u1 / scale[None, :]
         u2 = u2 / scale[None, :]
-        return DantzigState(z, w, u1, u2, new_rho), None
+        return DantzigState(z, w, u1, u2, new_rho)
 
-    if cfg.tol is None:
-        state, _ = jax.lax.scan(body, init, jnp.arange(cfg.max_iters))
+    def plain_steps(state: DantzigState, n: int) -> DantzigState:
+        if n == 0:
+            return state
+        return jax.lax.fori_loop(0, n, lambda _, s: step(s, False), state)
+
+    def step_at(state: DantzigState, i) -> DantzigState:
+        """Iteration ``i`` (traced): it adapts when ``i`` is a multiple of
+        ``adapt_every``.  The residual is computed on every iteration and
+        masked, not chosen by a ``lax.cond``: under ``vmap`` the early
+        exit batches ``i``, and a batched ``cond`` runs both steps."""
+        if not cfg.adapt_rho:
+            return step(state, False)
+        return step(state, i % cfg.adapt_every == 0)
+
+    if cfg.tol is None and not cfg.adapt_rho:
+        state = plain_steps(init, cfg.max_iters)
+        iters = jnp.int32(cfg.max_iters)
+    elif cfg.tol is None:
+        # the static schedule: iteration i adapts when i % adapt_every
+        # == 0, so each chunk is one adapting step and adapt_every - 1
+        # plain ones; a tail of max_iters % adapt_every starts at a
+        # multiple of adapt_every and so adapts first too.
+        every = cfg.adapt_every
+        chunks, tail = divmod(cfg.max_iters, every)
+
+        def chunk(state, _):
+            return plain_steps(step(state, True), every - 1), None
+
+        state, _ = jax.lax.scan(chunk, init, None, length=chunks)
+        if tail:
+            state = plain_steps(step(state, True), tail - 1)
         iters = jnp.int32(cfg.max_iters)
     else:
         # residual-gated early exit, mirroring the fused kernel's
@@ -279,7 +326,7 @@ def solve_dantzig_scan(
 
             def inner(j, c):
                 state, _, _ = c
-                new, _ = body(state, it + j)
+                new = step_at(state, it + j)
                 return new, new.z - state.z, new.w - state.w
 
             state, dz, dw = jax.lax.fori_loop(

@@ -15,15 +15,25 @@ The DESIGN.md §7 contract, pinned:
     bit-exact -- the adaptive machinery is strictly opt-in.
 """
 
+from collections import Counter
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.analysis import count_executions, find_eqns
 from repro.core import path as rpath
 from repro.core.clime import solve_clime_columns
 from repro.core.pipeline import BinaryHead
-from repro.core.dantzig import AdmmState, DantzigConfig, solve_dantzig_scan
+from repro.core.dantzig import (
+    AdmmState,
+    DantzigConfig,
+    _mm,
+    soft_threshold,
+    solve_dantzig_scan,
+)
 from repro.core.solver_dispatch import (
     select_solver,
     solve_dantzig,
@@ -327,3 +337,203 @@ def test_default_config_stays_on_the_fixed_kernel_bit_exact():
     via_full = solve_dantzig_full(factor, b, LAM, cfg)
     np.testing.assert_array_equal(np.asarray(base), np.asarray(via_full.beta))
     assert int(via_full.iters.max()) == 150
+
+
+# ---------------------------------------------------------------------------
+# residual balancing evaluated only on the adapting iterations
+# ---------------------------------------------------------------------------
+
+
+@partial(jax.jit, static_argnames=("cfg",))
+def _every_iteration_scan(factor, b, lam, cfg, rho0=None, state0=None):
+    """The scan solver as it was when every iteration evaluated the
+    residual-balancing statistics and masked them with ``i %
+    adapt_every == 0``: the yardstick the chunked schedule must match.
+    Returns ``(beta, rho, (z, w, u1, u2), iters)`` for a (d, k) ``b``."""
+    d, k = b.shape
+    a, q = factor.sigma, factor.q
+    inv_eig = factor.inv_eig[:, None]
+    lam = jnp.broadcast_to(jnp.asarray(lam, a.dtype), (k,))[None, :]
+
+    def solve_m(v):
+        return _mm(q, inv_eig * _mm(q.T, v))
+
+    zeros = jnp.zeros((d, k), a.dtype)
+    rho = (jnp.full((k,), cfg.rho, a.dtype) if rho0 is None
+           else jnp.broadcast_to(jnp.asarray(rho0, a.dtype), (k,)))
+    init = (zeros,) * 4 if state0 is None else tuple(state0)
+    init = init + (rho,)
+    alpha = cfg.alpha
+
+    def body(state, i):
+        z0, w0, u1_0, u2_0, rho = state
+        beta = solve_m(_mm(a, z0 + b - u1_0) + (w0 - u2_0))
+        ab = _mm(a, beta)
+        ab_r = alpha * ab + (1.0 - alpha) * (z0 + b)
+        beta_r = alpha * beta + (1.0 - alpha) * w0
+        z = jnp.clip(ab_r - b + u1_0, -lam, lam)
+        w = soft_threshold(beta_r + u2_0, 1.0 / rho[None, :], cfg.use_kernel)
+        u1 = u1_0 + ab_r - z - b
+        u2 = u2_0 + beta_r - w
+        if not cfg.adapt_rho:
+            return (z, w, u1, u2, rho), None
+        r_pri = jnp.sqrt(jnp.sum((ab - z - b) ** 2 + (beta - w) ** 2, axis=0))
+        s_dual = rho * jnp.sqrt(
+            jnp.sum(_mm(a, z - z0) ** 2 + (w - w0) ** 2, axis=0))
+        up = r_pri > cfg.rho_mu * s_dual
+        down = s_dual > cfg.rho_mu * r_pri
+        do_adapt = (i % cfg.adapt_every) == 0
+        scale = jnp.where(do_adapt & up, cfg.rho_tau,
+                          jnp.where(do_adapt & down, 1.0 / cfg.rho_tau, 1.0))
+        return (z, w, u1 / scale[None, :], u2 / scale[None, :],
+                rho * scale), None
+
+    if cfg.tol is None:
+        state, _ = jax.lax.scan(body, init, jnp.arange(cfg.max_iters))
+        iters = jnp.int32(cfg.max_iters)
+    else:
+        def chunk_body(carry):
+            it, state, _ = carry
+            n = jnp.minimum(jnp.int32(cfg.check_every), cfg.max_iters - it)
+
+            def inner(j, c):
+                state, _, _ = c
+                new, _ = body(state, it + j)
+                return new, new[0] - state[0], new[1] - state[1]
+
+            state, dz, dw = jax.lax.fori_loop(
+                0, n, inner, (state, zeros, zeros))
+            z, w, u1, u2, rho = state
+            beta = solve_m(_mm(a, z + b - u1) + (w - u2))
+            ab = _mm(a, beta)
+            r_pri = jnp.maximum(jnp.max(jnp.abs(ab - z - b)),
+                                jnp.max(jnp.abs(beta - w)))
+            s_dual = jnp.max(rho[None, :] * jnp.max(
+                jnp.abs(_mm(a, dz) + dw), axis=0, keepdims=True))
+            return it + n, state, jnp.maximum(r_pri, s_dual)
+
+        iters, state, _ = jax.lax.while_loop(
+            lambda c: jnp.logical_and(c[0] < cfg.max_iters, c[2] > cfg.tol),
+            chunk_body, (jnp.int32(0), init, jnp.asarray(jnp.inf, a.dtype)))
+    z, w, u1, u2, rho = state
+    return w, rho, (z, w, u1, u2), iters
+
+
+_SCHEDULES = {
+    "divisible": DantzigConfig(max_iters=60),
+    "tail": DantzigConfig(max_iters=67),
+    "adapt_every_1": DantzigConfig(max_iters=45, adapt_every=1),
+    "adapt_every_7_tail_1": DantzigConfig(max_iters=50, adapt_every=7),
+    "adapt_every_7_tail_3": DantzigConfig(max_iters=52, adapt_every=7),
+    "shorter_than_adapt_every": DantzigConfig(max_iters=6),
+    "fixed_rho": DantzigConfig(max_iters=67, adapt_rho=False),
+    # early exit at the file's converging operating point, checking
+    # on iterations that do not line up with the adapting ones
+    "tol": DantzigConfig(max_iters=FIXED, tol=TOL, check_every=4),
+    "tol_adapt_every_7": DantzigConfig(max_iters=FIXED, adapt_every=7,
+                                       tol=TOL, check_every=10),
+    "tol_fixed_rho": DantzigConfig(max_iters=FIXED, adapt_rho=False,
+                                   tol=TOL, check_every=7),
+    # the cap: the final chunk is clamped at max_iters
+    "tol_capped": DantzigConfig(max_iters=95, tol=1e-12, check_every=10),
+}
+_STARTS = ["cold", "rho0", "state0"]
+
+
+@pytest.mark.parametrize("start", _STARTS)
+@pytest.mark.parametrize("schedule", sorted(_SCHEDULES))
+def test_adapting_only_residual_matches_every_iteration(schedule, start):
+    """The residual product runs only on the iterations that adapt rho;
+    beta, rho, the state and the iteration count are those of the
+    schedule that evaluated it on every iteration."""
+    cfg = _SCHEDULES[schedule]
+    factor, b, lam = _factor(), _clime_b(k=6), LAM
+    kwargs = {}
+    if start == "rho0":
+        kwargs["rho0"] = jnp.asarray([1 / 64, 1 / 8, 1.0, 2.0, 16.0, 64.0])
+    elif start == "state0":
+        warm = solve_dantzig_scan(factor, b, lam, DantzigConfig(max_iters=23),
+                                  return_info=True)[1]
+        kwargs["state0"] = warm
+    beta, rho, state, iters = solve_dantzig_scan(
+        factor, b, lam, cfg, return_rho=True, return_info=True, **kwargs)
+    ref_beta, ref_rho, ref_state, ref_iters = _every_iteration_scan(
+        factor, b, lam, cfg, kwargs.get("rho0"),
+        None if start != "state0" else tuple(kwargs["state0"]))
+    np.testing.assert_array_equal(np.asarray(rho), np.asarray(ref_rho))
+    if start == "rho0" and cfg.adapt_rho:  # rho was balanced up and down
+        assert rho[0] > kwargs["rho0"][0] and rho[-1] < kwargs["rho0"][-1]
+    assert int(iters) == int(ref_iters)
+    np.testing.assert_allclose(np.asarray(beta), np.asarray(ref_beta),
+                               rtol=0, atol=1e-6)
+    for got, want in zip(state, ref_state):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=0, atol=1e-6)
+    # the narrow returns carry the same answer
+    np.testing.assert_array_equal(
+        np.asarray(solve_dantzig_scan(factor, b, lam, cfg, **kwargs)),
+        np.asarray(beta))
+    np.testing.assert_array_equal(
+        np.asarray(solve_dantzig_scan(factor, b, lam, cfg, return_rho=True,
+                                      **kwargs)[1]), np.asarray(rho))
+
+
+# where each (d,d)x(d,k) product sits: an adapting step holds five, a
+# plain step four; the tail's adapting step runs outside the chunk loop
+# and its plain steps in a loop of their own, at the chunk loop's depth
+_PRODUCT_SITES = {
+    "default": (DantzigConfig(), {("jit", "scan"): 5,
+                                  ("jit", "scan", "scan"): 4}),
+    "tail": (DantzigConfig(max_iters=67), {("jit",): 5, ("jit", "scan"): 9,
+                                           ("jit", "scan", "scan"): 4}),
+    "adapt_every_7": (DantzigConfig(max_iters=50, adapt_every=7),
+                      {("jit",): 5, ("jit", "scan"): 5,
+                       ("jit", "scan", "scan"): 4}),
+    "adapt_every_1": (DantzigConfig(max_iters=45, adapt_every=1),
+                      {("jit", "scan"): 5}),
+    "fixed_rho": (DantzigConfig(max_iters=67, adapt_rho=False),
+                  {("jit", "scan"): 4}),
+}
+
+
+@pytest.mark.parametrize("schedule", sorted(_PRODUCT_SITES))
+def test_residual_product_runs_once_per_adapting_iteration(schedule):
+    """The adapting step, with the residual's fifth product, sits in the
+    chunk loop and the four-product plain steps in its inner loop, so
+    the residual runs once per ``adapt_every`` iterations (the count
+    itself is the ``dantzig.solve_dantzig_scan`` trace contract).  At
+    the default config that is 60 per 600-iteration solve, where the
+    every-iteration schedule ran 600."""
+    cfg, want = _PRODUCT_SITES[schedule]
+    factor, b = _factor(d=16), _clime_b(d=16, k=4)
+    jaxpr = jax.make_jaxpr(
+        lambda f, b: solve_dantzig_scan(f, b, LAM, cfg))(factor, b)
+    sites = Counter(s.path for s in find_eqns(jaxpr, "dot_general", (16, 4)))
+    assert sites == want
+    if schedule == "default":
+        assert count_executions(jaxpr, "dot_general", (16, 4)) == 4 * 600 + 60
+        every = jax.make_jaxpr(
+            lambda f, b: _every_iteration_scan(f, b, LAM, cfg))(factor, b)
+        assert count_executions(every, "dot_general", (16, 4)) == 5 * 600
+
+
+@pytest.mark.parametrize("batched", [False, True], ids=["single", "vmap"])
+@pytest.mark.parametrize("schedule", ["tol", "tol_adapt_every_7"])
+def test_early_exit_step_holds_five_products(schedule, batched):
+    """The early exit's step evaluates the residual on every iteration
+    and masks it, as the every-iteration schedule did: five products.
+    Under ``vmap`` the exit batches the iteration index, and a
+    ``lax.cond`` choosing between the two steps would run both (nine)."""
+    cfg = _SCHEDULES[schedule]
+    factor, b = _factor(d=16), _clime_b(d=16, k=4)
+
+    def solve(f, b):
+        return solve_dantzig_scan(f, b, LAM, cfg)
+
+    if batched:
+        solve = jax.vmap(solve)
+        factor, b = jax.tree.map(lambda x: jnp.stack([x, x]), (factor, b))
+    jaxpr = jax.make_jaxpr(solve)(factor, b)
+    sites = Counter(s.path for s in find_eqns(jaxpr, "dot_general"))
+    # the inner loop's step, and the chunk's residual check
+    assert sites == {("jit", "while", "while"): 5, ("jit", "while"): 5}

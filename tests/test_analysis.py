@@ -12,16 +12,19 @@ import sys
 
 import jax
 import jax.numpy as jnp
+import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.analysis import (
     AxisPayloadBits,
     CollectiveContract,
     DtypePolicy,
+    ExecutionBudget,
     Param,
     PrimitiveBudget,
     check_entry,
     count_eqns,
+    count_executions,
     find_eqns,
     run_contracts,
     trace_contract,
@@ -116,6 +119,50 @@ def test_budget_param_resolution_and_missing_param():
     assert run_contracts([budget], jaxpr, {"eighs": 2}) != []
     (violation,) = run_contracts([budget], jaxpr, {})
     assert "eighs" in violation.message  # missing key is itself reported
+
+
+# ---------------------------------------------------------------------------
+# execution budgets: sites weighted by their enclosing scans' trips
+# ---------------------------------------------------------------------------
+
+
+def _nested_scans(a):
+    def inner(c, _):
+        return c @ c, None
+
+    def outer(c, _):
+        c = c @ c  # once per outer trip
+        c, _ = jax.lax.scan(inner, c, None, length=4)
+        return c, None
+
+    c, _ = jax.lax.scan(outer, a, None, length=3)
+    return jax.jit(lambda x: x @ x)(c)  # once
+
+
+def test_count_executions_multiplies_nested_scan_lengths():
+    jaxpr = jax.make_jaxpr(_nested_scans)(jnp.eye(2))
+    assert count_eqns(jaxpr, "dot_general") == 3
+    assert count_executions(jaxpr, "dot_general") == 3 + 3 * 4 + 1
+    assert count_executions(jaxpr, "dot_general", (5, 5)) == 0
+
+
+def test_execution_budget_exact_and_unknown_trips():
+    jaxpr = jax.make_jaxpr(_nested_scans)(jnp.eye(2))
+    budget = ExecutionBudget("dot_general", exact=Param("n"))
+    assert run_contracts([budget], jaxpr, {"n": 16}) == []
+    (violation,) = run_contracts([budget], jaxpr, {"n": 15})
+    assert "runs 16 times" in violation.message
+    assert len(violation.sites) == 3
+
+    def in_while(a):
+        return jax.lax.while_loop(lambda c: c[0, 0] < 9.0,
+                                  lambda c: c @ c + 1.0, a)
+
+    looped = jax.make_jaxpr(in_while)(jnp.eye(2))
+    with pytest.raises(ValueError, match="no static trip count"):
+        count_executions(looped, "dot_general")
+    (violation,) = budget.check(looped, {"n": 1})
+    assert "while/dot_general" in violation.message
 
 
 # ---------------------------------------------------------------------------

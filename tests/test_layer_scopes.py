@@ -140,9 +140,15 @@ def test_all_six_layers_compute(case):
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_one_admm_loop_per_solve(case):
-    loops = [scopes_of(name) for op, name in compiled(case)
+    """Each solve runs one loop over its adapting chunks, which holds the
+    one inner loop of plain steps; both carry the solve's scope."""
+    loops = [name for op, name in compiled(case)
              if op == "while" and "solve_dantzig_scan" in name]
-    assert sorted(loops) == [["clime"], ["direction"]]
+    outer = [n for n in loops if n.endswith("solve_dantzig_scan)/while")]
+    assert sorted(scopes_of(n) for n in outer) == [["clime"], ["direction"]]
+    inner = sorted(n for n in loops if n not in outer)
+    assert [n.split("/while/body/")[0] for n in inner] == sorted(
+        n.removesuffix("/while") for n in outer)
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
