@@ -377,14 +377,21 @@ def apply_correction(
     :func:`worker_solves`) each device contributes its (cols, K) slice,
     pad rows are masked to zero, and one intra-machine ``all_gather``
     over the model axis reassembles the full vector -- global column j
-    lands at row j, pad columns sit at rows >= d and are dropped.
+    lands at row j, pad columns sit at rows >= d and are dropped.  The
+    gather carries the ``slda.gather`` scope where the axis spans more
+    than one device; on a one-device axis it moves nothing.
     """
     if model_axis is None:
         return theta.T @ resid
     corr_slice = jnp.where(valid[:, None], theta.T @ resid, 0.0)
-    gathered = jax.lax.all_gather(
-        corr_slice, model_axis, axis=0, tiled=True
-    )  # (size * cols_per, K), device i's block at [i*cols_per, ...)
+
+    def gather():  # (size * cols_per, K), device i's block at [i*cols_per, ...)
+        return jax.lax.all_gather(corr_slice, model_axis, axis=0, tiled=True)
+
+    if jax.lax.axis_size(model_axis) == 1:
+        return gather()[: resid.shape[0]]
+    with jax.named_scope("slda.gather"):
+        gathered = gather()
     return gathered[: resid.shape[0]]
 
 

@@ -4,18 +4,20 @@ The pipeline names its layers with ``jax.named_scope`` (``slda.stats``,
 ``slda.spectral``, ``slda.direction``, ``slda.clime``, ``slda.debias``,
 ``slda.aggregate``), and a profiler trace attributes device time to a
 layer by reading the innermost scope out of each operation's
-``op_name``.  The one nesting is each round's ``slda.debias`` inside
-the rounds' ``slda.aggregate``.  Here the fit is compiled at a small
-size and each instruction the program executes (the entry computation
-and the loop bodies, not the inside of fusions) that computes is
-checked: where JAX gave it an ``op_name`` that ends in a primitive,
-that name holds a scope, and holds two only as that nesting.  What XLA
-adds itself (copies, bitcasts, rewrites) has no ``op_name``, and a
-constant JAX hoists is named by its ``jit(...)`` wrapper alone: both
-are exempt.
+``op_name``.  The nestings are each round's ``slda.debias`` inside the
+rounds' ``slda.aggregate`` and, on a mesh whose model axis spans more
+than one device, the correction's ``slda.gather`` inside the debias.
+Here the fit is compiled at a small size and each instruction the
+program executes (the entry computation and the loop bodies, not the
+inside of fusions) that computes is checked: where JAX gave it an
+``op_name`` that ends in a primitive, that name holds a scope, and
+holds more only as those nestings.  What XLA adds itself (copies,
+bitcasts, rewrites) has no ``op_name``, and a constant JAX hoists is
+named by its ``jit(...)`` wrapper alone: both are exempt.
 """
 
 import functools
+import json
 import re
 
 import jax
@@ -30,8 +32,11 @@ from repro.core.distributed import (
     simulated_distributed_slda,
 )
 
+from conftest import run_in_subprocess
+
 SCOPES = {"stats", "spectral", "direction", "clime", "debias", "aggregate"}
-NESTED = ["aggregate", "debias"]  # a round's debias, inside the rounds
+NESTED = (["aggregate", "debias"],  # a round's debias, inside the rounds
+          ["aggregate", "debias", "gather"])  # ... and its model gather
 D, N, M = 16, 40, 3
 
 _COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
@@ -99,16 +104,50 @@ def _simulated_fit():
     return simulated_distributed_slda.lower(xs, xs, 0.1, 0.1, 0.05)
 
 
+MODEL_AXIS = f"""
+import contextlib, json
+import jax, jax.numpy as jnp
+from repro.core.dantzig import DantzigConfig
+from repro.core.distributed import distributed_slda_shardmap
+
+mesh = jax.make_mesh((2, 2), ("data", "model"))
+x = jax.ShapeDtypeStruct((2 * {N}, {D - 1}), jnp.float32)
+
+
+def hlo():
+    fn = jax.jit(lambda x, y, lam, t: distributed_slda_shardmap(
+        mesh, x, y, lam, lam, t, DantzigConfig(), rounds=3))
+    return fn.lower(x, x, 0.1, 0.05).compile().as_text()
+
+
+scoped = hlo()
+named_scope = jax.named_scope
+jax.named_scope = lambda name: (contextlib.nullcontext()
+                                if name == "slda.gather"
+                                else named_scope(name))
+print(json.dumps({{"scoped": scoped, "unscoped": hlo()}}))
+"""
+
+
+@functools.lru_cache(maxsize=None)
+def model_axis_hlo() -> dict:
+    """The fit on a (data 2, model 2) mesh at an odd d, T=3, compiled with
+    and without the gather's scope, in a process with four devices."""
+    out = run_in_subprocess(MODEL_AXIS, devices=4)
+    return json.loads(out.strip().splitlines()[-1])
+
+
 CASES = {
-    "mesh_T1": lambda: _mesh_fit(1),
-    "mesh_T3": lambda: _mesh_fit(3),
-    "simulated": _simulated_fit,
+    "mesh_T1": lambda: _mesh_fit(1).compile().as_text(),
+    "mesh_T3": lambda: _mesh_fit(3).compile().as_text(),
+    "simulated": lambda: _simulated_fit().compile().as_text(),
+    "model_axis_T3": lambda: model_axis_hlo()["scoped"],
 }
 
 
 @functools.lru_cache(maxsize=None)
 def compiled(case: str) -> list:
-    return executed_instructions(CASES[case]().compile().as_text())
+    return executed_instructions(CASES[case]())
 
 
 def scopes_of(op_name: str) -> list:
@@ -127,7 +166,7 @@ def test_every_named_instruction_has_one_scope(case):
              if from_primitive(name) and op not in MOVES]
     assert named
     stray = [(op, name) for op, name in named
-             if len(scopes_of(name)) != 1 and scopes_of(name) != NESTED]
+             if len(scopes_of(name)) != 1 and scopes_of(name) not in NESTED]
     assert not stray, stray[:10]
 
 
@@ -135,7 +174,37 @@ def test_every_named_instruction_has_one_scope(case):
 def test_all_six_layers_compute(case):
     seen = {scopes_of(name)[-1] for op, name in compiled(case)
             if op not in MOVES and scopes_of(name)}
-    assert seen == SCOPES
+    assert seen == SCOPES | ({"gather"} if case == "model_axis_T3"
+                             else set())
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_gather_scope_only_on_a_model_axis(case):
+    """``slda.gather`` names each round's all-gather over a model axis of
+    two devices, and nothing where the axis holds one device (or none)."""
+    gathers = [scopes_of(name) for op, name in compiled(case)
+               if "slda.gather" in name]
+    if case == "model_axis_T3":
+        assert gathers == [NESTED[1]] * 3
+        assert [op for op, name in compiled(case)
+                if "slda.gather" in name] == ["all-gather"] * 3
+    else:
+        assert gathers == []
+
+
+def strip_metadata(text: str) -> str:
+    """A compiled module's text without what names and locates its
+    instructions in the source: the metadata and the stack tables."""
+    text = re.sub(r"^(FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                  r"(?:.+\n)*", "", text, flags=re.M)
+    return re.sub(r",? metadata=\{[^}]*\}", "", text)
+
+
+def test_gather_scope_changes_no_compiled_code():
+    hlo = model_axis_hlo()
+    assert hlo["scoped"] != hlo["unscoped"]
+    assert strip_metadata(hlo["scoped"]) == strip_metadata(hlo["unscoped"])
+    assert "all-gather" in strip_metadata(hlo["scoped"])
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
